@@ -205,4 +205,7 @@ def run(smoke: bool = False) -> dict:
 
 if __name__ == "__main__":
     import sys
+
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run(smoke="--smoke" in sys.argv)

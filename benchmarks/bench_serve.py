@@ -1156,5 +1156,7 @@ if __name__ == "__main__":
                     help="comma list from %s (default: all)"
                     % ",".join(ALL_SECTIONS))
     a = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run(smoke=a.smoke,
         sections=a.sections.split(",") if a.sections else None)

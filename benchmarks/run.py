@@ -23,6 +23,9 @@ def main() -> None:
     import benchmarks.bench_table1 as table1
     import benchmarks.bench_theory as theory
     import benchmarks.roofline as roofline
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     suites = {
         "theory": (theory.run, lambda r: "thm4.2-verified"),

@@ -6,8 +6,9 @@ sublane dim of the score matmul (G × blk_s), so each kv block is read
 ONCE per group instead of once per query head (cuts HBM traffic by
 H/Hkv — the roofline term that dominates decode_32k).
 
-Grid (B, Hkv, nS) with the cache axis minor-most; running max/sum/acc in
-VMEM scratch. ``kv_mask`` carries ring-buffer validity + window masking
+Grid (B, nS) with the cache axis minor-most; each step loads every kv
+head of one cache block, with running max/sum/acc per kv head in VMEM
+scratch. ``kv_mask`` carries ring-buffer validity + window masking
 computed by the caller.
 """
 from __future__ import annotations
@@ -24,7 +25,7 @@ NEG_INF = -1e30
 
 def _decode_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_scr, l_scr,
                    acc_scr, *, scale: float, ns: int):
-    isb = pl.program_id(2)
+    isb = pl.program_id(1)
 
     @pl.when(isb == 0)
     def _init():
@@ -32,27 +33,25 @@ def _decode_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_scr, l_scr,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale            # (G, hd)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)              # (blk_s, hd)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    valid = mask_ref[0, :] > 0                             # (blk_s,)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (G, blk_s)
-    s = jnp.where(valid[None, :], s, NEG_INF)
-
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    alpha = jnp.exp(m_prev - m_new)
-    l_new = alpha * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
-    acc = acc_scr[...] * alpha + jax.lax.dot(p, v)
-    m_scr[...] = m_new
-    l_scr[...] = l_new
-    acc_scr[...] = acc
+    valid = mask_ref[0] > 0                                # (1, blk_s)
+    for h in range(k_ref.shape[2]):        # static unroll over kv heads
+        q = q_ref[0, h].astype(jnp.float32) * scale        # (G, hd)
+        k = k_ref[0, :, h, :].astype(jnp.float32)          # (blk_s, hd)
+        v = v_ref[0, :, h, :].astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))
+        s = jnp.where(valid, s, NEG_INF)                   # (G, blk_s)
+        m_prev = m_scr[h]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[h] = alpha * l_scr[h] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot(p, v)
+        m_scr[h] = m_new
 
     @pl.when(isb == ns - 1)
     def _finish():
-        o_ref[0, 0] = (acc_scr[...]
-                       / jnp.maximum(l_scr[...], 1e-20)).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...]
+                    / jnp.maximum(l_scr[...], 1e-20)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("blk_s", "interpret"))
@@ -75,24 +74,27 @@ def decode_attention(q, k, v, kv_mask, *, blk_s: int = 256,
     ns = Sp // blk_s
     # group query heads by kv head: (B, Hkv, G, hd)
     qg = q[:, 0].reshape(B, Hkv, G, hd)
-    maskf = kv_mask.astype(jnp.float32)
+    # (B, 1, S): the mask block's last two dims are (1, blk_s)
+    maskf = kv_mask.astype(jnp.float32)[:, None, :]
 
     kernel = functools.partial(_decode_kernel, scale=scale, ns=ns)
+    # Each grid step moves a (blk_s, Hkv, hd) slab, every kv head at
+    # once: the block's last two dims equal the cache's (Mosaic tiling).
     out = pl.pallas_call(
         kernel,
-        grid=(B, Hkv, ns),
+        grid=(B, ns),
         in_specs=[
-            pl.BlockSpec((1, 1, G, hd), lambda b, h, s: (b, h, 0, 0)),
-            pl.BlockSpec((1, blk_s, 1, hd), lambda b, h, s: (b, s, h, 0)),
-            pl.BlockSpec((1, blk_s, 1, hd), lambda b, h, s: (b, s, h, 0)),
-            pl.BlockSpec((1, blk_s), lambda b, h, s: (b, s)),
+            pl.BlockSpec((1, Hkv, G, hd), lambda b, s: (b, 0, 0, 0)),
+            pl.BlockSpec((1, blk_s, Hkv, hd), lambda b, s: (b, s, 0, 0)),
+            pl.BlockSpec((1, blk_s, Hkv, hd), lambda b, s: (b, s, 0, 0)),
+            pl.BlockSpec((1, 1, blk_s), lambda b, s: (b, 0, s)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, h, s: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, Hkv, G, hd), lambda b, s: (b, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, hd), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, hd), jnp.float32),
+            pltpu.VMEM((Hkv, G, 1), jnp.float32),
+            pltpu.VMEM((Hkv, G, 1), jnp.float32),
+            pltpu.VMEM((Hkv, G, hd), jnp.float32),
         ],
         interpret=interpret,
     )(qg, k, v, maskf)

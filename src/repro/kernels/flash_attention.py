@@ -33,9 +33,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32) * scale      # (blk_q, hd)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)              # (blk_k, hd)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    q = q_ref[0, 0].astype(jnp.float32) * scale             # (blk_q, hd)
+    k = k_ref[0, 0].astype(jnp.float32)                     # (blk_k, hd)
+    v = v_ref[0, 0].astype(jnp.float32)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (blk_q, blk_k)
 
     q_pos = iq * blk_q + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
@@ -61,8 +61,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     @pl.when(ik == nk - 1)
     def _finish():
-        o_ref[0, :, 0, :] = (acc_scr[...]
-                             / jnp.maximum(l_scr[...], 1e-20)).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...]
+                       / jnp.maximum(l_scr[...], 1e-20)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "blk_q",
@@ -75,12 +75,16 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     scale = hd ** -0.5
     pad = (-L) % blk_q
     padk = (-L) % blk_k
+    # head-major inside the kernel: a (blk, hd) block of one head has
+    # last two dims Mosaic can tile, which a (blk, 1, hd) slice of the
+    # token-major layout does not
+    q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
     if pad or padk:
         # pad q to blk_q and kv to blk_k multiples; padded kv masked in-kernel
-        q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        k = jnp.pad(k, ((0, 0), (0, padk), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, padk), (0, 0), (0, 0)))
-    Lq, Lk = q.shape[1], k.shape[1]
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        k = jnp.pad(k, ((0, 0), (0, 0), (0, padk), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, 0), (0, padk), (0, 0)))
+    Lq, Lk = q.shape[2], k.shape[2]
     nq, nk = Lq // blk_q, Lk // blk_k
 
     kernel = functools.partial(
@@ -90,13 +94,13 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         kernel,
         grid=(B, H, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, blk_q, 1, hd), lambda b, h, iq, ik: (b, iq, h, 0)),
-            pl.BlockSpec((1, blk_k, 1, hd), lambda b, h, iq, ik: (b, ik, h, 0)),
-            pl.BlockSpec((1, blk_k, 1, hd), lambda b, h, iq, ik: (b, ik, h, 0)),
+            pl.BlockSpec((1, 1, blk_q, hd), lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec((1, 1, blk_k, hd), lambda b, h, iq, ik: (b, h, ik, 0)),
+            pl.BlockSpec((1, 1, blk_k, hd), lambda b, h, iq, ik: (b, h, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, blk_q, 1, hd),
-                               lambda b, h, iq, ik: (b, iq, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Lq, H, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, blk_q, hd),
+                               lambda b, h, iq, ik: (b, h, iq, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, Lq, hd), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((blk_q, 1), jnp.float32),
             pltpu.VMEM((blk_q, 1), jnp.float32),
@@ -104,4 +108,4 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         ],
         interpret=interpret,
     )(q, k, v)
-    return out[:, :L]
+    return out[:, :, :L].transpose(0, 2, 1, 3)

@@ -30,12 +30,8 @@ def _dispatch_kernel(idx_ref, x_ref, out_ref, *, C: int):
         for c in range(C):
             t = idx_ref[0, e, c]
             valid = t >= 0
-            # all-dslice index tuple: a bare int here breaks the jax 0.4.x
-            # interpret-mode load discharge rule
-            row = pl.load(x_ref, (pl.dslice(0, 1),
-                                  pl.dslice(jnp.maximum(t, 0), 1),
-                                  pl.dslice(0, d)))
-            out_ref[0, e, c, :] = jnp.where(valid, row[0, 0],
+            row = x_ref[0, pl.ds(jnp.maximum(t, 0), 1), :]     # (1, d)
+            out_ref[0, e, c, :] = jnp.where(valid, row[0],
                                             jnp.zeros((d,), out_ref.dtype))
 
 

@@ -1,9 +1,11 @@
 """Public jit'd entry points for the Pallas kernels.
 
-On a real TPU these dispatch compiled Mosaic kernels; everywhere else
-(including this CPU container and the multi-pod dry-run) they run the
-kernels in interpret mode or fall back to the jnp oracle — selectable via
-``REPRO_KERNEL_MODE`` in {"auto", "interpret", "ref"}.
+On a TPU these always dispatch the compiled Mosaic kernels. Everywhere
+else (the CPU test runs) they return the jnp oracle, or run the kernels
+in interpret mode when ``REPRO_KERNEL_MODE=interpret`` asks for it
+(``REPRO_KERNEL_MODE`` in {"auto", "interpret", "ref"}; auto = oracle).
+On a TPU any value but "auto" is an error: a run on the chip never
+measures an oracle or the interpreter in place of the kernel.
 """
 from __future__ import annotations
 
@@ -17,13 +19,21 @@ from repro.kernels import paged_decode_attention as _pdec
 from repro.kernels import ref as _ref
 from repro.kernels import xmodal_score as _xm
 
+_MODES = ("auto", "interpret", "ref")
+
 
 def _mode() -> str:
     m = os.environ.get("REPRO_KERNEL_MODE", "auto")
-    if m == "auto":
-        plat = jax.devices()[0].platform
-        return "tpu" if plat == "tpu" else "ref"
-    return m
+    if m not in _MODES:
+        raise ValueError(f"REPRO_KERNEL_MODE must be one of {_MODES}, "
+                         f"got {m!r}")
+    if jax.devices()[0].platform == "tpu":
+        if m != "auto":
+            raise RuntimeError(
+                f"REPRO_KERNEL_MODE={m!r} would replace the compiled "
+                "Pallas kernel on a TPU; unset it")
+        return "tpu"
+    return "ref" if m == "auto" else m
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,

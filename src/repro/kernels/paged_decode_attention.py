@@ -11,11 +11,12 @@ with *live* tokens — the roofline term that dominates decode.
 Mechanics: the block table and per-row live lengths arrive as
 scalar-prefetch operands (``pltpu.PrefetchScalarGridSpec``) so the page
 index feeds the BlockSpec index map — the DMA engine fetches exactly the
-page ``block_table[b, i]`` for grid step ``(b, h, i)``. Page-index is the
-minor-most grid dim; running max/sum/acc live in VMEM scratch exactly
-like the contiguous kernel, so fully-masked trailing pages wash out of
-the online softmax (alpha underflows to 0 when a real max arrives;
-garbage from a masked-prefix page is erased the same way).
+page ``block_table[b, i]`` (all kv heads) for grid step ``(b, i)``.
+Page-index is the minor-most grid dim; running max/sum/acc per kv head
+live in VMEM scratch like the contiguous kernel, so fully-masked
+trailing pages wash out of the online softmax (alpha underflows to 0
+when a real max arrives; garbage from a masked-prefix page is erased
+the same way).
 
 GQA-aware like ``_decode_kernel``: the G query heads of one kv head form
 the sublane dim of the score matmul, so each page is read once per
@@ -86,7 +87,7 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
     else:
         o_ref, m_scr, l_scr, acc_scr = rest
     b = pl.program_id(0)
-    i = pl.program_id(2)
+    i = pl.program_id(1)
 
     @pl.when(i == 0)
     def _init():
@@ -94,36 +95,34 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale            # (G, hd)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)              # (ps, hd)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    if quantized:
-        # per-(slot, kv-head) fp32 scales: (1, ps, 1) blocks -> (ps, 1)
-        k = k * ks_ref[0]
-        v = v * vs_ref[0]
     length = len_ref[b]
     # token j of logical page i sits at absolute position i*ps + j; only
     # positions below the row's live length attend. (>=2D iota for TPU.)
     pos = i * page_size + jax.lax.broadcasted_iota(
         jnp.int32, (1, page_size), 1)
     valid = pos < length                                   # (1, ps)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (G, ps)
-    s = jnp.where(valid, s, NEG_INF)
-
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    alpha = jnp.exp(m_prev - m_new)
-    l_new = alpha * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
-    acc = acc_scr[...] * alpha + jax.lax.dot(p, v)
-    m_scr[...] = m_new
-    l_scr[...] = l_new
-    acc_scr[...] = acc
+    for h in range(k_ref.shape[2]):        # static unroll over kv heads
+        q = q_ref[0, h].astype(jnp.float32) * scale        # (G, hd)
+        k = k_ref[0, :, h, :].astype(jnp.float32)          # (ps, hd)
+        v = v_ref[0, :, h, :].astype(jnp.float32)
+        if quantized:
+            # per-(slot, kv-head) fp32 scales: a (ps, 1) column
+            k = k * ks_ref[0, :, h:h + 1]
+            v = v * vs_ref[0, :, h:h + 1]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))
+        s = jnp.where(valid, s, NEG_INF)                   # (G, ps)
+        m_prev = m_scr[h]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[h] = alpha * l_scr[h] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot(p, v)
+        m_scr[h] = m_new
 
     @pl.when(i == n_pages - 1)
     def _finish():
-        o_ref[0, 0] = (acc_scr[...]
-                       / jnp.maximum(l_scr[...], 1e-20)).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...]
+                    / jnp.maximum(l_scr[...], 1e-20)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -142,10 +141,14 @@ def _paged_decode_call(q, k_pages, v_pages, block_table, lengths,
     kernel = functools.partial(_paged_decode_kernel, scale=scale,
                                page_size=ps, n_pages=n_pages,
                                quantized=quantized)
-    page_spec = pl.BlockSpec((1, ps, 1, hd),
-                             lambda b, h, i, bt, ln: (bt[b, i], 0, h, 0))
+    # Each grid step moves one whole page, every kv head at once. Mosaic
+    # tiles a block only if each of its last two dims is a multiple of
+    # (8, 128) or spans the array: a one-head (1, hd) block does not,
+    # the whole (Hkv, hd) page row does.
+    page_spec = pl.BlockSpec((1, ps, Hkv, hd),
+                             lambda b, i, bt, ln: (bt[b, i], 0, 0, 0))
     in_specs = [
-        pl.BlockSpec((1, 1, G, hd), lambda b, h, i, bt, ln: (b, h, 0, 0)),
+        pl.BlockSpec((1, Hkv, G, hd), lambda b, i, bt, ln: (b, 0, 0, 0)),
         page_spec,
         page_spec,
     ]
@@ -153,19 +156,19 @@ def _paged_decode_call(q, k_pages, v_pages, block_table, lengths,
     if quantized:
         # scale blocks ride the same block-table index map as their page
         scale_spec = pl.BlockSpec(
-            (1, ps, 1), lambda b, h, i, bt, ln: (bt[b, i], 0, h))
+            (1, ps, Hkv), lambda b, i, bt, ln: (bt[b, i], 0, 0))
         in_specs += [scale_spec, scale_spec]
         operands += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                 # block table + lengths
-        grid=(B, Hkv, n_pages),
+        grid=(B, n_pages),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, G, hd),
-                               lambda b, h, i, bt, ln: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, Hkv, G, hd),
+                               lambda b, i, bt, ln: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, hd), jnp.float32),
+            pltpu.VMEM((Hkv, G, 1), jnp.float32),
+            pltpu.VMEM((Hkv, G, 1), jnp.float32),
+            pltpu.VMEM((Hkv, G, hd), jnp.float32),
         ],
     )
     out = pl.pallas_call(

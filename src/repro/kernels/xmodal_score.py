@@ -29,6 +29,11 @@ def _norm_rows(x, eps=1e-8):
     return x / jnp.maximum(n, eps)
 
 
+def _sum2(x):
+    """Sum of a 2-D tile as a (1, 1) tile — the accumulator's shape."""
+    return jnp.sum(jnp.sum(x, axis=1, keepdims=True), axis=0, keepdims=True)
+
+
 def _mean_kernel(tok_ref, tmask_ref, vis_ref, vmask_ref, o_ref, acc_scr, *,
                  nl: int, nv: int):
     """Accumulates sum_t sum_j cos(tok_t, vis_j) over valid pairs."""
@@ -41,15 +46,14 @@ def _mean_kernel(tok_ref, tmask_ref, vis_ref, vmask_ref, o_ref, acc_scr, *,
 
     tok = _norm_rows(tok_ref[0].astype(jnp.float32))        # (blk_l, d)
     vis = _norm_rows(vis_ref[0].astype(jnp.float32))        # (blk_v, d)
-    tm = tmask_ref[0]                                       # (blk_l,)
-    vm = vmask_ref[0]                                       # (blk_v,)
+    tm = tmask_ref[0]                                       # (blk_l, 1)
+    vm = vmask_ref[0]                                       # (1, blk_v)
     sims = jax.lax.dot_general(tok, vis, (((1,), (1,)), ((), ())))
-    sims = sims * tm[:, None] * vm[None, :]
-    acc_scr[0, 0] += jnp.sum(sims)
+    acc_scr[...] += _sum2(sims * tm * vm)
 
     @pl.when((il == nl - 1) & (iv == nv - 1))
     def _finish():
-        o_ref[0, 0] = acc_scr[0, 0]
+        o_ref[0] = acc_scr[...]
 
 
 def _max_kernel(txt_ref, tmask_ref, vis_ref, vmask_ref, o_ref, max_scr,
@@ -68,20 +72,19 @@ def _max_kernel(txt_ref, tmask_ref, vis_ref, vmask_ref, o_ref, max_scr,
 
     txt = _norm_rows(txt_ref[0].astype(jnp.float32))        # (blk_t, d)
     vis = _norm_rows(vis_ref[0].astype(jnp.float32))        # (blk_v, d)
-    vm = vmask_ref[0] > 0
+    vm = vmask_ref[0] > 0                                   # (1, blk_v)
     sims = jax.lax.dot_general(txt, vis, (((1,), (1,)), ((), ())))
-    sims = jnp.where(vm[None, :], sims, NEG_INF)
+    sims = jnp.where(vm, sims, NEG_INF)
     max_scr[...] = jnp.maximum(max_scr[...],
                                jnp.max(sims, axis=-1, keepdims=True))
 
     @pl.when(iv == nv - 1)
     def _row_finish():
-        tm = tmask_ref[0]
-        acc_scr[0, 0] += jnp.sum(max_scr[:, 0] * tm)
+        acc_scr[...] += _sum2(max_scr[...] * tmask_ref[0])
 
     @pl.when((it == nt - 1) & (iv == nv - 1))
     def _finish():
-        o_ref[0, 0] = acc_scr[0, 0]
+        o_ref[0] = acc_scr[...]
 
 
 def _pad_to(x, n, axis):
@@ -102,11 +105,14 @@ def xmodal_score(token_embs, mask, visual_feats, text_feats, *,
     Nv = visual_feats.shape[1]
     Nt = text_feats.shape[1]
     tok = _pad_to(token_embs, blk, 1)
-    tm = _pad_to(mask.astype(jnp.float32), blk, 1)
     vis = _pad_to(visual_feats, blk, 1)
-    vm = _pad_to(jnp.ones((B, Nv), jnp.float32), blk, 1)
     txt = _pad_to(text_feats, blk, 1)
-    xm = _pad_to(jnp.ones((B, Nt), jnp.float32), blk, 1)
+    # row masks (token, text) are (B, n, 1) columns and the visual mask a
+    # (B, 1, n) row, so each mask block's last two dims tile on TPU and
+    # broadcast against the (rows, visual) similarity tile directly
+    tm = _pad_to(mask.astype(jnp.float32), blk, 1)[:, :, None]
+    xm = _pad_to(jnp.ones((B, Nt), jnp.float32), blk, 1)[:, :, None]
+    vm = _pad_to(jnp.ones((B, Nv), jnp.float32), blk, 1)[:, None, :]
     nl, nv, nt = tok.shape[1] // blk, vis.shape[1] // blk, txt.shape[1] // blk
 
     sum1 = pl.pallas_call(
@@ -114,12 +120,12 @@ def xmodal_score(token_embs, mask, visual_feats, text_feats, *,
         grid=(B, nl, nv),
         in_specs=[
             pl.BlockSpec((1, blk, d), lambda b, il, iv: (b, il, 0)),
-            pl.BlockSpec((1, blk), lambda b, il, iv: (b, il)),
+            pl.BlockSpec((1, blk, 1), lambda b, il, iv: (b, il, 0)),
             pl.BlockSpec((1, blk, d), lambda b, il, iv: (b, iv, 0)),
-            pl.BlockSpec((1, blk), lambda b, il, iv: (b, iv)),
+            pl.BlockSpec((1, 1, blk), lambda b, il, iv: (b, 0, iv)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda b, il, iv: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, 1), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, 1), lambda b, il, iv: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, 1, 1), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, 1), jnp.float32)],
         interpret=interpret,
     )(tok, tm, vis, vm)
@@ -129,18 +135,18 @@ def xmodal_score(token_embs, mask, visual_feats, text_feats, *,
         grid=(B, nt, nv),
         in_specs=[
             pl.BlockSpec((1, blk, d), lambda b, it, iv: (b, it, 0)),
-            pl.BlockSpec((1, blk), lambda b, it, iv: (b, it)),
+            pl.BlockSpec((1, blk, 1), lambda b, it, iv: (b, it, 0)),
             pl.BlockSpec((1, blk, d), lambda b, it, iv: (b, iv, 0)),
-            pl.BlockSpec((1, blk), lambda b, it, iv: (b, iv)),
+            pl.BlockSpec((1, 1, blk), lambda b, it, iv: (b, 0, iv)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda b, it, iv: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, 1), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, 1), lambda b, it, iv: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, 1, 1), jnp.float32),
         scratch_shapes=[pltpu.VMEM((blk, 1), jnp.float32),
                         pltpu.VMEM((1, 1), jnp.float32)],
         interpret=interpret,
     )(txt, xm, vis, vm)
 
     n_tok = jnp.maximum(jnp.sum(mask.astype(jnp.float32), axis=-1), 1.0)
-    term1 = sum1[:, 0] / (n_tok * Nv)
-    term2 = sum2[:, 0] / Nt
+    term1 = sum1[:, 0, 0] / (n_tok * Nv)
+    term2 = sum2[:, 0, 0] / Nt
     return 0.5 * (term1 + term2)

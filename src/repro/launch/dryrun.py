@@ -137,8 +137,6 @@ def _compile(cfg, shape, mesh, *, unroll=False, microbatches=None):
 
 def _extract(compiled) -> Dict[str, float]:
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):   # jax <= 0.4.x: one dict per device
-        cost = cost[0] if cost else {}
     text = compiled.as_text()
     coll = hlo_utils.collective_bytes(text)
     return {

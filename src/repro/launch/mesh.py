@@ -6,18 +6,12 @@ state — the dry-run must set XLA_FLAGS before the first jax call.
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5 makes mesh axis types explicit; 0.4.x is Auto-only.
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - version-dependent
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -3,6 +3,11 @@
     python -m repro.launch.serve --arch qwen3-0.6b --reduced --mode camd \
         --requests 8 --impl paged --page-size 16
 
+``--reduced`` (the default) serves the config's CPU-sized variant in
+float32; ``--no-reduced`` serves its published widths in the config's
+dtype (bf16). ``build_model``/``build_engine``/``make_requests`` are the
+one construction path, shared with ``chip_smoke.py``.
+
 ``--open-loop`` serves the same requests through the async streaming
 front-end as a timed arrival process (``--arrival poisson|bursty`` at
 ``--arrival-rate`` rps) and prints SLO metrics — TTFT/TPOT percentiles
@@ -18,17 +23,22 @@ import jax.numpy as jnp
 from repro.config import (CAMDConfig, PagedKVConfig, SamplingConfig,
                           VisionConfig)
 from repro.configs import get_config
-from repro.models import build_model
+from repro.launch.compile_cache import enable_compile_cache
+from repro.models import build_model as _build_model
 from repro.serving import Request, ServeEngine
 from repro.training import load_checkpoint
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", "--config", default="qwen3-0.6b",
                     help="arch id ('llava-1.5-7b') or config module name "
                          "('llava_1_5_7b') — both spellings resolve")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the config's CPU-sized variant in float32 "
+                         "(default); --no-reduced serves its published "
+                         "widths in the config's dtype")
     ap.add_argument("--image-tokens", type=int, default=0,
                     help="multimodal serving: encode synthetic images "
                          "through the config's vision tower into N "
@@ -135,13 +145,18 @@ def main():
                     help="open-loop offered load, requests/s")
     ap.add_argument("--slo-ms", type=float, default=500.0,
                     help="TTFT SLO for the goodput metric, milliseconds")
-    ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seeds params, synthetic requests and sampling")
+    return ap
 
+
+def build_model(args):
+    """(cfg, model, params) for ``--arch``/``--reduced``/``--image-tokens``:
+    reduced runs in float32, full width in the config's dtype, params
+    drawn from ``--seed`` (or loaded from ``--ckpt``)."""
     cfg = get_config(args.arch)
     if args.reduced:
-        cfg = cfg.reduced()
-    cfg = cfg.with_overrides(dtype="float32")
+        cfg = cfg.reduced().with_overrides(dtype="float32")
     if args.image_tokens:
         if cfg.vision is None:
             raise SystemExit(f"--image-tokens needs a vision config; "
@@ -152,11 +167,22 @@ def main():
             vision=VisionConfig.for_tokens(
                 args.image_tokens, patch=v.patch, num_layers=v.num_layers,
                 d_model=v.d_model, num_heads=v.num_heads, d_ff=v.d_ff))
-    model = build_model(cfg, jnp.float32)
-    params = model.init(jax.random.PRNGKey(0))
+    model = _build_model(cfg)
+    params = jax.jit(model.init)(jax.random.PRNGKey(args.seed))
     if args.ckpt:
         params, _ = load_checkpoint(args.ckpt, params)
+    return cfg, model, params
 
+
+def cache_len_for(cfg, args) -> int:
+    """Slot cache depth: prompt + image tokens + ``--max-new``, rounded
+    up to the page size."""
+    n = args.prompt_len + cfg.num_evidence_tokens + args.max_new
+    return -(-n // args.page_size) * args.page_size
+
+
+def build_engine(args, model, params) -> ServeEngine:
+    """The serving engine the CLI flags describe (mesh included)."""
     mesh = None
     if args.mesh or args.serve_dp > 1:
         from repro.launch.mesh import make_serve_mesh
@@ -168,8 +194,9 @@ def main():
         print(f"serving mesh: {dict(mesh.shape)} over "
               f"{len(jax.devices())} {jax.default_backend()} devices")
 
-    eng = ServeEngine(
-        model, params, slots=args.slots, cache_len=128,
+    return ServeEngine(
+        model, params, slots=args.slots,
+        cache_len=cache_len_for(model.cfg, args),
         sampling=SamplingConfig(max_new_tokens=args.max_new),
         camd=CAMDConfig(),
         mode=args.mode, max_new_tokens=args.max_new, eos_id=1,
@@ -192,6 +219,12 @@ def main():
         spec_mode=args.spec_mode,
         xmodal_rescore=args.xmodal_rescore,
         seed=args.seed)
+
+
+def make_requests(args, cfg):
+    """``--requests`` synthetic requests drawn from ``--seed``: random
+    prompts, plus an image from a pool of ``--image-pool`` distinct
+    images (vision configs) or random evidence rows (evidence configs)."""
     rng = np.random.default_rng(args.seed)
 
     images = []
@@ -215,12 +248,21 @@ def main():
                 (cfg.num_evidence_tokens, cfg.evidence_dim)).astype(np.float32)
         return Request(uid=i, prompt=prompt, evidence=ev)
 
+    return [mk_request(i) for i in range(args.requests)]
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
+    cfg, model, params = build_model(args)
+    eng = build_engine(args, model, params)
+    reqs = make_requests(args, cfg)
+
     if args.open_loop:
         from repro.serving.traffic import ARRIVALS, run_open_loop
         if args.macro_steps < 1:
             raise SystemExit("--open-loop drives the fused macro-step "
                              "loop; use --macro-steps >= 1")
-        reqs = [mk_request(i) for i in range(args.requests)]
         arrivals = ARRIVALS[args.arrival](
             args.arrival_rate, args.requests, seed=args.seed)
         traces, metrics = run_open_loop(eng, reqs, arrivals,
@@ -242,8 +284,8 @@ def main():
               f"{metrics['tokens_per_s']:.1f} tok/s")
         results = []
     else:
-        for i in range(args.requests):
-            eng.submit(mk_request(i))
+        for r in reqs:
+            eng.submit(r)
         results = eng.run()
     for r in results:
         print(f"req {r.uid}: candidates={r.n_candidates} rounds={r.rounds} "
