@@ -1,0 +1,56 @@
+"""Seeded weights for the system under test, made by the benchmark.
+
+The program is asked only for the *layout* of its parameters (the shapes
+and dtypes of ``build_model``'s tree, traced abstractly, nothing
+compiled or allocated); every value is drawn here from ``--seed``, on the
+device, in one jitted call, in the dtype it is served in. The reference
+reads the same arrays, so it takes nothing the program made.
+
+Scales follow the usual initialisation of each kind of leaf: matrices
+N(0, 1/d_in), embedding tables N(0, 1/d), norm scales 1, biases 0.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+
+def seed_key(seed: int):
+    """A PRNG key for any whole number, including ones past 32 bits."""
+    key = jax.random.PRNGKey(seed & 0x7FFFFFFF)
+    return jax.random.fold_in(key, (seed >> 31) & 0x7FFFFFFF)
+
+
+def _leaf_name(path) -> str:
+    return "/".join(str(getattr(p, "key", getattr(p, "idx", p)))
+                    for p in path)
+
+
+def _draw(key, name: str, shape, dtype):
+    last = name.rsplit("/", 1)[-1]
+    if last in ("scale", "q_norm", "k_norm"):
+        return jnp.ones(shape, dtype)
+    if last == "bias":
+        return jnp.zeros(shape, dtype)
+    if last == "table":
+        std = shape[-1] ** -0.5
+    elif last == "kernel":
+        std = shape[-2] ** -0.5
+    else:
+        raise ValueError(f"no initialisation rule for parameter {name}")
+    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
+
+
+def make_params(shapes, seed: int):
+    """Arrays shaped like ``shapes`` (a tree of ShapeDtypeStruct), drawn
+    from ``seed`` in one jitted call."""
+    flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
+    specs = [(_leaf_name(p), s.shape, s.dtype) for p, s in flat]
+
+    @jax.jit
+    def draw(key):
+        keys = jax.random.split(key, len(specs))
+        return [_draw(k, n, sh, dt) for k, (n, sh, dt) in zip(keys, specs)]
+
+    leaves = draw(seed_key(seed))
+    return jax.tree_util.tree_unflatten(treedef, leaves)
