@@ -70,6 +70,7 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib as _hashlib
+import time
 from functools import partial
 from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
@@ -87,6 +88,7 @@ from repro.sampling.samplers import (decode_step_key, sample_token,
 from repro.serving.page_pool import PagePool, prefix_page_keys
 from repro.serving.scheduler import (NewWork, PrefillWork, RoundWork,
                                      SchedulerContext, make_scheduler)
+from repro.serving.spans import Spans
 from repro.serving.state_arena import StateArena
 
 
@@ -435,7 +437,11 @@ class ServeEngine:
         self._suffix_fn = self._build_suffix_prefill() \
             if (self.prefix_cache or self.chunked) else None
         self._greedy_row = jnp.asarray([self.mode == "greedy"])
-        self._round_fn = jax.jit(ctrl.batched_round_update_assign(self.camd))
+        round_fn = ctrl.batched_round_update_assign(self.camd)
+
+        def round_update(states, inps):
+            return round_fn(states, inps)
+        self._round_fn = jax.jit(round_update)
         self._dummy_frontier = jnp.zeros((slots, 1), jnp.int32)
         # telemetry: total_steps counts device decode steps;
         # macro_launches counts while_loop dispatches; host_syncs counts
@@ -448,6 +454,17 @@ class ServeEngine:
         # speculation telemetry: drafts proposed / drafts accepted
         self.spec_drafted = 0
         self.spec_accepted = 0
+        # host-loop telemetry: the host's time from the end of one
+        # launch's readback to the next launch's dispatch (gaps in which
+        # the engine had no live slot and no queued work are left out),
+        # and each request's wait from submit to its first admission
+        self.spans = Spans()
+        self.launch_gap_ns = 0
+        self.launch_gaps = 0
+        self.queue_wait_ns = 0
+        self.first_admissions = 0
+        self._sync_end: Optional[int] = None
+        self._t_submit: Dict[int, int] = {}
         # async front-end plumbing: opt-in per-launch token streaming
         # (readbacks ride the launch sync — no extra host syncs), a
         # completion feed the front-end drains between launches, and
@@ -585,32 +602,32 @@ class ServeEngine:
         model = self.model
 
         @jax.jit
-        def prefill(params, tokens, cache_row, evidence=None):
+        def prefill_row(params, tokens, cache_row, evidence=None):
             lg, h, cache = model.prefill(params, tokens, cache_row,
                                          evidence, impl=self._model_impl)
             return lg, h, cache
 
-        return prefill
+        return prefill_row
 
     def _build_bucket_prefill(self):
         model, impl = self.model, self._model_impl
 
         @jax.jit
-        def prefill(params, tokens, lengths, cache, evidence=None):
+        def prefill_bucket(params, tokens, lengths, cache, evidence=None):
             return model.prefill(params, tokens, cache, evidence,
                                  impl=impl, lengths=lengths)
 
-        return prefill
+        return prefill_bucket
 
     def _build_first_tokens(self):
         sampling = self.sampling
 
         @jax.jit
-        def first(keys, logits, bias, greedy):
+        def first_tokens(keys, logits, bias, greedy):
             return sample_token_batch(keys, logits, sampling, bias=bias,
                                       greedy=greedy)
 
-        return first
+        return first_tokens
 
     def _build_suffix_prefill(self):
         """Continuation prefill for prefix-cache hits: only the prompt
@@ -619,11 +636,11 @@ class ServeEngine:
         model, impl = self.model, self._model_impl
 
         @jax.jit
-        def suffix(params, tokens, cache_row, ctx, start):
+        def prefill_suffix(params, tokens, cache_row, ctx, start):
             return model.prefill_suffix(params, tokens, cache_row, ctx,
                                         start, impl=impl)
 
-        return suffix
+        return prefill_suffix
 
     def _make_step_body(self):
         """One decode+sample+aggregate step — the body shared by the
@@ -632,7 +649,7 @@ class ServeEngine:
             self.eos_id, self.max_new
         has_ev = self.has_evidence
 
-        def step(params, st: EngineState, key, evid_norm):
+        def decode_step(params, st: EngineState, key, evid_norm):
             logits, hidden, cache = model.decode_step(
                 params, st.last_token, st.cache, impl=self._model_impl)
             tok, lp = sample_token(key, logits.astype(jnp.float32), sampling,
@@ -678,7 +695,7 @@ class ServeEngine:
                 spec_k=st.spec_k)
             return new_state, done
 
-        return step
+        return decode_step
 
     def _build_macro_step(self):
         """Fused decode loop: up to K steps of ``_step_body`` inside
@@ -698,7 +715,8 @@ class ServeEngine:
         B = self.B
 
         @partial(jax.jit, donate_argnums=(1,))
-        def macro(params, st: EngineState, base_key, t0, evid_norm, frontier):
+        def decode_launch(params, st: EngineState, base_key, t0, evid_norm,
+                          frontier):
             F = frontier.shape[1]
 
             def cond(carry):
@@ -728,7 +746,7 @@ class ServeEngine:
             st, fidx, done, i = jax.lax.while_loop(cond, body, carry)
             return st, done, i
 
-        return macro
+        return decode_launch
 
     def _coverage_k(self, p_star) -> int:
         """Per-candidate speculative verify width (1..spec_k).
@@ -824,8 +842,8 @@ class ServeEngine:
         all_greedy = self.mode == "greedy"
 
         @partial(jax.jit, donate_argnums=(1,))
-        def macro(params, st: EngineState, base_key, t0, evid_norm,
-                  frontier):
+        def decode_launch(params, st: EngineState, base_key, t0, evid_norm,
+                          frontier):
             F = frontier.shape[1]
             # first logical page the frontier row maps to (fixed at
             # launch start — frontier entries are indexed by logical
@@ -942,7 +960,7 @@ class ServeEngine:
             st, done, i, nd, na = jax.lax.while_loop(cond, body, carry)
             return st, done, i, nd, na
 
-        return macro
+        return decode_launch
 
     # ------------------------------------------------------------------
     # host-side scheduling
@@ -957,6 +975,7 @@ class ServeEngine:
             self._encode_image(req)
         self._arrival[req.uid] = self._submit_seq
         self._submit_seq += 1
+        self._t_submit[req.uid] = time.perf_counter_ns()
         self._queue.append(req)
 
     # -- image frontend ------------------------------------------------
@@ -1486,7 +1505,8 @@ class ServeEngine:
 
     def sched_stats(self) -> Dict[str, Any]:
         """Traffic-policy telemetry: budget accounting, admissions,
-        declined rounds, starvation, cancellations."""
+        declined rounds, starvation, cancellations, the queue wait of
+        first admissions, and the host loop's gaps between launches."""
         s = dict(self.scheduler.stats())
         s["starved"] = len(self.starved_uids)
         s["prefill_calls"] = self.prefill_calls
@@ -1496,7 +1516,24 @@ class ServeEngine:
         s["cancelled_requests"] = self.cancelled_requests
         s["image_encodes"] = self.image_encodes
         s["image_feat_hits"] = self.image_feat_hits
+        s["queue_wait_ns"] = self.queue_wait_ns
+        s["first_admissions"] = self.first_admissions
+        s["launch_gap_ns"] = self.launch_gap_ns
+        s["launch_gaps"] = self.launch_gaps
         return s
+
+    def span_stats(self) -> Dict[str, Any]:
+        """Host-loop telemetry: the launch-gap and queue-wait counters
+        (also in ``sched_stats``), each ``serve.*`` span's count, total
+        and max ns, and the most recent pump's ns with its direct
+        children's (``last_pump``; None before the first pump)."""
+        st = self.spans.stats()
+        return {"launch_gap_ns": self.launch_gap_ns,
+                "launch_gaps": self.launch_gaps,
+                "queue_wait_ns": self.queue_wait_ns,
+                "first_admissions": self.first_admissions,
+                "spans": st["spans"],
+                "last_pump": st["last"].get("serve.pump")}
 
     def arena_stats(self) -> Dict[str, Any]:
         """Fixed-stride state-arena telemetry (recurrent/hybrid
@@ -1533,6 +1570,12 @@ class ServeEngine:
         self.cancelled_requests = 0
         self.image_encodes = 0
         self.image_feat_hits = 0
+        self.launch_gap_ns = 0
+        self.launch_gaps = 0
+        self.queue_wait_ns = 0
+        self.first_admissions = 0
+        self._sync_end = None
+        self.spans.reset()
         self.starved_uids.clear()
         self.scheduler.reset_stats()
         if self.paged:
@@ -1570,6 +1613,10 @@ class ServeEngine:
         per-candidate token grant (``None`` = the engine-wide max)."""
         lim = self.max_new if limit is None else min(int(limit), self.max_new)
         assert lim >= 1
+        t_submit = self._t_submit.pop(req.uid, None)
+        if t_submit is not None:
+            self.queue_wait_ns += time.perf_counter_ns() - t_submit
+            self.first_admissions += 1
         info = self._reqs[req.uid]
         st = self.state
         if self.spec and not self.paged:
@@ -1719,15 +1766,17 @@ class ServeEngine:
     def _prefill_request(self, req: Request):
         """Unbucketed fallback: one prefill call per request (recompiles
         per distinct prompt length)."""
-        prompt = jnp.asarray(req.prompt, jnp.int32)[None, :]
-        cache_row = self.model.make_cache(1, self.cache_len, self._dtype)
-        ev = None
-        if req.evidence is not None:
-            ev = jnp.asarray(req.evidence, self._dtype)[None]
-        lg, h, cache_row = self._prefill_fn(self.params, prompt, cache_row, ev)
-        self.prefill_calls += 1
-        self.prefill_tokens += self._prompt_span(req)
-        self._init_info(req, cache_row, lg, h, self._prompt_span(req))
+        with self.spans("serve.prefill", rows=1, length=len(req.prompt)):
+            prompt = jnp.asarray(req.prompt, jnp.int32)[None, :]
+            cache_row = self.model.make_cache(1, self.cache_len, self._dtype)
+            ev = None
+            if req.evidence is not None:
+                ev = jnp.asarray(req.evidence, self._dtype)[None]
+            lg, h, cache_row = self._prefill_fn(self.params, prompt,
+                                                cache_row, ev)
+            self.prefill_calls += 1
+            self.prefill_tokens += self._prompt_span(req)
+            self._init_info(req, cache_row, lg, h, self._prompt_span(req))
 
     # -- cross-request prefix cache ------------------------------------
     def _mark_cacheable(self, req: Request):
@@ -1769,14 +1818,15 @@ class ServeEngine:
         if start < ne:
             self.pool.free(pages)        # partial image hit: re-prefill
             return False
-        suffix = jnp.asarray(stream[start:], jnp.int32)[None, :]
-        ctx = self._gather_prefix_ctx(pages)
-        cache_row = self.model.make_cache(1, self.cache_len, self._dtype)
-        lg, h, cache_row = self._suffix_fn(
-            self.params, suffix, cache_row, ctx, jnp.int32(start))
-        self.prefill_calls += 1
-        self.prefill_tokens += len(stream) - start          # suffix only
-        self._init_info(req, cache_row, lg, h, len(stream))
+        with self.spans("serve.prefill", rows=1, length=len(stream) - start):
+            suffix = jnp.asarray(stream[start:], jnp.int32)[None, :]
+            ctx = self._gather_prefix_ctx(pages)
+            cache_row = self.model.make_cache(1, self.cache_len, self._dtype)
+            lg, h, cache_row = self._suffix_fn(
+                self.params, suffix, cache_row, ctx, jnp.int32(start))
+            self.prefill_calls += 1
+            self.prefill_tokens += len(stream) - start      # suffix only
+            self._init_info(req, cache_row, lg, h, len(stream))
         info = self._reqs[req.uid]
         info["prompt_pages"] = pages         # request hold already taken
         info["prefix_len"] = start
@@ -1963,7 +2013,9 @@ class ServeEngine:
                     break
                 if not idle and self._chunk_left <= 0:
                     return
-                took = self._run_chunk(w.uid, job)
+                with self.spans("serve.prefill", uid=w.uid, rows=1,
+                                length=self.chunk):
+                    took = self._run_chunk(w.uid, job)
                 if took == 0:
                     break            # shard can't fund the chunk yet
                 self._chunk_left -= took
@@ -2029,7 +2081,8 @@ class ServeEngine:
                 for r in reqs:
                     self._prefill_request(r)
             else:
-                self._prefill_bucket(Lb, ne, reqs)
+                with self.spans("serve.prefill", rows=len(reqs), length=Lb):
+                    self._prefill_bucket(Lb, ne, reqs)
             for r in reqs:
                 self._mark_cacheable(r)
 
@@ -2076,8 +2129,9 @@ class ServeEngine:
         cover its candidates' worst-case pages (``_paged_affordable``);
         otherwise it waits in the queue / stays pending until running
         candidates finish and return pages."""
-        self._prefill_pending()
-        self.scheduler.schedule(_EngineSchedContext(self))
+        with self.spans("serve.schedule"):
+            self._prefill_pending()
+            self.scheduler.schedule(_EngineSchedContext(self))
 
     def _needed(self, info) -> int:
         if self.mode == "camd":
@@ -2272,6 +2326,7 @@ class ServeEngine:
         info = self._reqs[uid]
         info["done"] = True
         info["pending_round"] = False
+        self._t_submit.pop(uid, None)     # never admitted (cancel, drain)
         info["cache_row"] = None          # free the prompt cache
         r = info.pop("arena_row", None)
         if r is not None:
@@ -2384,24 +2439,30 @@ class ServeEngine:
         self._chunk_left = self.chunk_budget     # per-turn chunk budget
         if not self._any_live():
             if self._refill_idle():
+                self._sync_end = None            # idle: no launch gap
                 return False
             if self.has_evidence:
                 self._evid = self._gather_evid()
             return True
-        staged, frontier = (self._stage_frontier() if self.paged
-                            else (None, self._dummy_frontier))
-        if self._frontier_sharding is not None:
-            frontier = jax.device_put(frontier, self._frontier_sharding)
-        self._reshard()
-        if self.spec:
-            self.state, done, steps, nd, na = self._macro_fn(
-                self.params, self.state, self._decode_key,
-                jnp.int32(self._t), self._evid, frontier)
-        else:
-            self.state, done, steps = self._macro_fn(
-                self.params, self.state, self._decode_key,
-                jnp.int32(self._t), self._evid, frontier)
-        self.macro_launches += 1
+        with self.spans("serve.stage"):
+            staged, frontier = (self._stage_frontier() if self.paged
+                                else (None, self._dummy_frontier))
+            if self._frontier_sharding is not None:
+                frontier = jax.device_put(frontier, self._frontier_sharding)
+            self._reshard()
+        with self.spans("serve.launch"):
+            if self._sync_end is not None:
+                self.launch_gap_ns += time.perf_counter_ns() - self._sync_end
+                self.launch_gaps += 1
+            if self.spec:
+                self.state, done, steps, nd, na = self._macro_fn(
+                    self.params, self.state, self._decode_key,
+                    jnp.int32(self._t), self._evid, frontier)
+            else:
+                self.state, done, steps = self._macro_fn(
+                    self.params, self.state, self._decode_key,
+                    jnp.int32(self._t), self._evid, frontier)
+            self.macro_launches += 1
         # ONE host sync per launch: cancellation emission counts and
         # streaming readbacks ride the tuple the fold already needs
         tree = [done, self.state.cache["pos"], steps]
@@ -2412,7 +2473,9 @@ class ServeEngine:
             tree.append(self.state.n_tok)
         if self.stream_tokens:
             tree.append(self.state.out_buf)
-        vals = self._sync(tuple(tree))
+        with self.spans("serve.sync"):
+            vals = self._sync(tuple(tree))
+        self._sync_end = time.perf_counter_ns()
         done_np, pos_np, steps_np = vals[0], vals[1], vals[2]
         k = 3
         if self.spec:
@@ -2425,17 +2488,19 @@ class ServeEngine:
         self.total_steps += steps_n
         # each speculative iteration consumes spec_k fold-in keys
         self._t += steps_n * (self.spec_k if self.spec else 1)
-        cancelled = self._apply_cancels(staged, ntok_np) \
-            if self._cancels else False
-        if self.stream_tokens:
-            self._emit_stream(ntok_np, out_np)
-        if self.paged:
-            self._reclaim_frontier(staged, pos_np)
+        with self.spans("serve.fold"):
+            cancelled = self._apply_cancels(staged, ntok_np) \
+                if self._cancels else False
+            if self.stream_tokens:
+                self._emit_stream(ntok_np, out_np)
+            if self.paged:
+                self._reclaim_frontier(staged, pos_np)
         done_slots = [int(s) for s in np.nonzero(done_np)[0]
                       if self._slot_req[s] >= 0]
         if done_slots or cancelled:
             if done_slots:
-                self._finish_candidates(done_slots)
+                with self.spans("serve.finish", candidates=len(done_slots)):
+                    self._finish_candidates(done_slots)
             self._schedule()
             if self.has_evidence:
                 self._evid = self._gather_evid()
@@ -2445,6 +2510,8 @@ class ServeEngine:
             # spend this turn's chunk budget between decode launches —
             # the stall-free interleaving the chunking exists for
             self._schedule()
+        if not self.has_work():
+            self._sync_end = None                # idle: no launch gap
         return True
 
     def pump(self) -> bool:
@@ -2459,13 +2526,14 @@ class ServeEngine:
             raise RuntimeError(
                 "pump() drives the fused macro-step loop; construct the "
                 "engine with macro_steps >= 1 for async serving")
-        if self._evid is None:
-            self._begin()
-        elif (self._queue and self._free_slots()) or self._chunking:
-            self._schedule()
-            if self.has_evidence and self._any_live():
-                self._evid = self._gather_evid()
-        return self._step()
+        with self.spans("serve.pump"):
+            if self._evid is None:
+                self._begin()
+            elif (self._queue and self._free_slots()) or self._chunking:
+                self._schedule()
+                if self.has_evidence and self._any_live():
+                    self._evid = self._gather_evid()
+            return self._step()
 
     def _emit_stream(self, ntok_np, out_np):
         """Queue per-slot token deltas for the async front-end. Deltas
@@ -2751,14 +2819,17 @@ class _EngineSchedContext(SchedulerContext):
     def admit_new(self, uid: int, take: int, limit: int) -> None:
         eng = self.eng
         i = next(i for i, r in enumerate(eng._queue) if r.uid == uid)
-        req = eng._queue.pop(i)
-        eng._admit(req, eng._free_slots()[:take], limit=limit)
+        self._admit(eng._queue.pop(i), take, limit)
 
     def admit_round(self, uid: int, take: int, limit: int) -> None:
-        eng = self.eng
-        info = eng._reqs[uid]
+        info = self.eng._reqs[uid]
         info["pending_round"] = False
-        eng._admit(info["req"], eng._free_slots()[:take], limit=limit)
+        self._admit(info["req"], take, limit)
+
+    def _admit(self, req: Request, take: int, limit: int) -> None:
+        eng = self.eng
+        with eng.spans("serve.admit", uid=req.uid, candidates=take):
+            eng._admit(req, eng._free_slots()[:take], limit=limit)
 
     def finish_request(self, uid: int) -> None:
         self.eng._finish_request(uid)

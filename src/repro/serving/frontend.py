@@ -167,25 +167,26 @@ class AsyncServeFrontend:
 
     def _dispatch(self) -> None:
         eng = self.engine
-        for uid, _cand, toks in eng.drain_stream_events():
-            q = self._queues.get(uid)
-            if q is None or uid in self._closed:
-                continue
-            for t in np.asarray(toks).tolist():
-                q.put_nowait(int(t))
-        for uid in eng.pop_finished():
-            fut = self._futs.get(uid)
-            if fut is None:
-                continue               # finished outside this front-end
-            res = eng.result(uid)
-            if not fut.done():
-                fut.set_result(res)
-            q = self._queues.get(uid)
-            if q is not None and uid not in self._closed \
-                    and not self._incremental and not res.cancelled:
-                for t in np.asarray(res.tokens).tolist():
+        with eng.spans("serve.dispatch"):
+            for uid, _cand, toks in eng.drain_stream_events():
+                q = self._queues.get(uid)
+                if q is None or uid in self._closed:
+                    continue
+                for t in np.asarray(toks).tolist():
                     q.put_nowait(int(t))
-            self._close_stream(uid)
+            for uid in eng.pop_finished():
+                fut = self._futs.get(uid)
+                if fut is None:
+                    continue               # finished outside this front-end
+                res = eng.result(uid)
+                if not fut.done():
+                    fut.set_result(res)
+                q = self._queues.get(uid)
+                if q is not None and uid not in self._closed \
+                        and not self._incremental and not res.cancelled:
+                    for t in np.asarray(res.tokens).tolist():
+                        q.put_nowait(int(t))
+                self._close_stream(uid)
 
     # -- internals ------------------------------------------------------
     def _close_stream(self, uid: int) -> None:
