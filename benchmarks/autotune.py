@@ -13,8 +13,8 @@ Two sweeps, one artifact:
   then the prefill chunk size on the head-of-line latency cell
   (``bench_serve._run_chunked_cell``). These are genuine wall-clock
   measurements on every backend. The paged decode kernel has no
-  independent block knob — its grid IS (batch, kv_head, page), so
-  page_size doubles as its block size and this sweep covers it.
+  block knob: it derives its pages per block from the page size
+  (``paged_decode_attention.BLOCK_TOKENS`` tokens a block).
 
 Writes ``BENCH_autotune.json``. ``load_tuned()`` merges that file over
 the builtin defaults; ``bench_serve`` / ``bench_kernels`` call it so a

@@ -463,6 +463,11 @@ class ServeEngine:
         self.launch_gaps = 0
         self.queue_wait_ns = 0
         self.first_admissions = 0
+        # paged decode walk per launch, from the host's slot positions:
+        # table pages holding a live token of an active slot (what the
+        # kernel reads) against the table's whole width (B x pages/slot)
+        self.decode_pages_live = 0
+        self.decode_pages_table = 0
         self._sync_end: Optional[int] = None
         self._t_submit: Dict[int, int] = {}
         # async front-end plumbing: opt-in per-launch token streaming
@@ -1411,6 +1416,14 @@ class ServeEngine:
             staged[s] = (p, pages)
         return staged, jnp.asarray(fr)
 
+    def _count_decode_pages(self):
+        """Add this launch's first step to the decode-walk counters: each
+        active slot's live pages (its length, the token the step writes
+        included, over the page size) and the table's width."""
+        lengths = self._slot_pos[self._slot_req >= 0] + 1
+        self.decode_pages_live += int(np.sum(-(-lengths // self.page_size)))
+        self.decode_pages_table += self.B * self.pages_per_slot
+
     def _reclaim_frontier(self, staged, pos_np):
         """After a macro-step: keep the consumed frontier prefix as slot
         pages (the device advanced the block table through them, in
@@ -1489,6 +1502,8 @@ class ServeEngine:
         stats["resident_kv_bytes"] = stats["in_use"] * bpp
         stats["peak_kv_bytes"] = stats["max_in_use"] * bpp
         stats["dense_equiv_bytes"] = self.B * self.pages_per_slot * bpp
+        stats["decode_pages_live"] = self.decode_pages_live
+        stats["decode_pages_table"] = self.decode_pages_table
         if self.pool.prefix is not None:
             pc = self.pool.prefix
             stats["prefix_cache"] = {
@@ -1574,6 +1589,8 @@ class ServeEngine:
         self.launch_gaps = 0
         self.queue_wait_ns = 0
         self.first_admissions = 0
+        self.decode_pages_live = 0
+        self.decode_pages_table = 0
         self._sync_end = None
         self.spans.reset()
         self.starved_uids.clear()
@@ -2454,6 +2471,8 @@ class ServeEngine:
             if self._sync_end is not None:
                 self.launch_gap_ns += time.perf_counter_ns() - self._sync_end
                 self.launch_gaps += 1
+            if self.paged:
+                self._count_decode_pages()
             if self.spec:
                 self.state, done, steps, nd, na = self._macro_fn(
                     self.params, self.state, self._decode_key,

@@ -78,3 +78,27 @@ def mk_engine():
 @pytest.fixture(scope="session")
 def submit_prompts():
     return _submit
+
+
+# ---------------------------------------------------------------------------
+# Paged decode kernel cases (test_paged_attention.py, test_kv_quant.py)
+# ---------------------------------------------------------------------------
+
+def block_edge_lengths(ps, n_pages):
+    """One row per edge of the kernel's first block: one token, the
+    block less one, the block, the block and one, the whole table."""
+    from repro.kernels.paged_decode_attention import _pages_per_block
+    T = _pages_per_block(ps, n_pages) * ps
+    assert T < n_pages * ps, "table must be wider than one block"
+    return jnp.asarray([1, T - 1, T, T + 1, n_pages * ps], jnp.int32)
+
+
+def nan_dead_pages(pages, block_table, lengths, ps):
+    """``pages`` with every page that no live table entry names set to
+    NaN: the dead entries' pages, page 0 and the spare ones."""
+    bt, ln = np.asarray(block_table), np.asarray(lengths)
+    live = np.arange(bt.shape[1])[None, :] * ps < ln[:, None]
+    dead = np.ones(pages.shape[0], bool)
+    dead[bt[live]] = False
+    assert dead[bt[~live]].all()
+    return pages.at[jnp.asarray(dead)].set(jnp.nan)

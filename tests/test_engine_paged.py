@@ -134,6 +134,40 @@ def test_impossible_pool_raises_sizing_error(small_model):
         eng.run()
 
 
+def test_decode_page_counters_match_slot_positions(small_model):
+    """``kv_stats()``'s decode-walk counters equal a count made at each
+    launch from the device's own slot state: the active slots' live
+    pages (length = position + 1, over the page size) and B x the table
+    width."""
+    cfg, model, params = small_model
+    ps = 4
+    eng = _mk_engine(model, params, mode="camd", impl="paged",
+                     paged_kv=PagedKVConfig(page_size=ps), macro_steps=2)
+    seen = {"live": 0, "table": 0}
+    launch = eng._macro_fn
+
+    def counted(params_, state, *args):
+        act = np.asarray(state.active)
+        pos = np.asarray(state.cache["pos"])[act]
+        seen["live"] += int(sum(-(-(p + 1) // ps) for p in pos))
+        seen["table"] += eng.B * state.cache["block_table"].shape[1]
+        return launch(params_, state, *args)
+
+    eng._macro_fn = counted
+    _submit(eng, cfg, 3)
+    eng.run()
+    stats = eng.kv_stats()
+    assert eng.macro_launches > 2
+    assert seen["live"] > eng.macro_launches     # slots span several pages
+    assert stats["decode_pages_live"] == seen["live"]
+    assert stats["decode_pages_table"] == seen["table"]
+    assert stats["decode_pages_table"] == \
+        eng.macro_launches * eng.B * (eng.cache_len // ps)
+    eng.reset_stats()
+    assert eng.kv_stats()["decode_pages_live"] == 0
+    assert eng.kv_stats()["decode_pages_table"] == 0
+
+
 def test_paged_pallas_kernel_path_runs(small_model):
     """impl="paged_pallas" (block-table kernel via ops dispatch) completes
     the same workload with plausible outputs."""

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import block_edge_lengths, nan_dead_pages
 from repro.configs import get_config
 from repro.kernels import ops, ref
 from repro.kernels.paged_decode_attention import (paged_decode_attention,
@@ -155,6 +156,34 @@ def test_fp8_paged_kernel_matches_fp32_oracle(ps):
     out = paged_decode_attention(q, kq, vq, bt, lengths, k_scale=ks,
                                  v_scale=vs, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp), **FP8_TOLS)
+
+
+@pytest.mark.parametrize("qdtype", QDTYPES, ids=lambda d: jnp.dtype(d).name)
+@pytest.mark.parametrize("ps,n_pages", [(16, 21), (64, 5)])
+def test_quantized_paged_kernel_multi_block(qdtype, ps, n_pages):
+    """Several kernel blocks per row, lengths at the block edges, and
+    NaN scales on every page no live entry names: the scales ride the
+    pages' copies, so a finite output equal to the clean pool's proves
+    no dead scale page was read either."""
+    B, H, Hkv, hd = 5, 8, 2, 64
+    P = B * n_pages + 2
+    key = jax.random.PRNGKey(ps + n_pages)
+    q, kp, vp, bt = _setup(key, B, H, Hkv, hd, P, ps, n_pages)
+    lengths = block_edge_lengths(ps, n_pages)
+    exp = ref.paged_decode_attention_ref(q, kp, vp, bt, lengths)
+    kq, ks = kv_quantize(kp, qdtype)
+    vq, vs = kv_quantize(vp, qdtype)
+    clean = ref.paged_decode_attention_ref(q, kq, vq, bt, lengths,
+                                           k_scale=ks, v_scale=vs)
+    out = paged_decode_attention(q, kq, vq, bt, lengths,
+                                 k_scale=nan_dead_pages(ks, bt, lengths, ps),
+                                 v_scale=nan_dead_pages(vs, bt, lengths, ps),
+                                 interpret=True)
+    assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_allclose(np.asarray(out), np.asarray(clean),
+                               rtol=2e-5, atol=2e-5)
+    tols = INT8_TOLS if jnp.dtype(qdtype) == jnp.dtype(jnp.int8) else FP8_TOLS
+    np.testing.assert_allclose(np.asarray(out), np.asarray(exp), **tols)
 
 
 def test_ops_dispatch_passes_scales(monkeypatch):

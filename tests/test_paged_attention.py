@@ -2,13 +2,17 @@
 paged oracle vs contiguous oracle, and the paged cache-write layout.
 
 Sweeps page sizes {16, 64, 128}, ragged live lengths, and GQA group
-sizes — the block-padding and masking paths the serving engine leans on.
+sizes — the block-padding and masking paths the serving engine leans on
+— then tables several kernel blocks wide, lengths at the block edges,
+and dead table entries naming NaN pages, which the kernel must never
+read.
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import block_edge_lengths, nan_dead_pages
 from repro.configs import get_config
 from repro.kernels import ref
 from repro.kernels.paged_decode_attention import paged_decode_attention
@@ -47,6 +51,43 @@ def test_paged_kernel_matches_oracle(ps, Hkv, H, dtype):
     exp = ref.paged_decode_attention_ref(q, kp, vp, bt, lengths)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(exp, np.float32), **TOLS[dtype])
+
+
+# tables wider than one kernel block; the block does not divide 21 or 5
+MULTI_BLOCK = [(16, 80), (16, 21), (64, 5)]
+
+
+@pytest.mark.parametrize("ps,n_pages", MULTI_BLOCK)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_kernel_multi_block(ps, n_pages, dtype):
+    B, H, Hkv, hd = 5, 8, 2, 64
+    P = B * n_pages + 2
+    key = jax.random.PRNGKey(ps + n_pages)
+    q, kp, vp, bt = _setup(key, B, H, Hkv, hd, P, ps, n_pages, dtype)
+    lengths = block_edge_lengths(ps, n_pages)
+    out = paged_decode_attention(q, kp, vp, bt, lengths, interpret=True)
+    exp = ref.paged_decode_attention_ref(q, kp, vp, bt, lengths)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(exp, np.float32), **TOLS[dtype])
+
+
+@pytest.mark.parametrize("ps,n_pages", MULTI_BLOCK)
+def test_paged_kernel_never_reads_dead_pages(ps, n_pages):
+    """Dead table entries name NaN pages: one NaN read anywhere would
+    reach the output (0 * NaN is NaN), so a finite output equal to the
+    oracle's on the clean pool proves no dead page was read."""
+    B, H, Hkv, hd = 5, 8, 2, 64
+    P = B * n_pages + 2
+    key = jax.random.PRNGKey(ps * n_pages)
+    q, kp, vp, bt = _setup(key, B, H, Hkv, hd, P, ps, n_pages, jnp.float32)
+    lengths = block_edge_lengths(ps, n_pages)
+    exp = ref.paged_decode_attention_ref(q, kp, vp, bt, lengths)
+    out = paged_decode_attention(q, nan_dead_pages(kp, bt, lengths, ps),
+                                 nan_dead_pages(vp, bt, lengths, ps), bt,
+                                 lengths, interpret=True)
+    assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
+                               **TOLS[jnp.float32])
 
 
 def test_paged_oracle_matches_contiguous_oracle():

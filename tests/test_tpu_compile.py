@@ -4,11 +4,16 @@ Interpret mode never sees Mosaic's tiling rules or VMEM limits; these
 tests hand each kernel to the TPU compiler (installed with jax, no chip
 needed) at internvl2-2b's published widths — H=16, Hkv=8, hd=128,
 d_model=2048, a 256-token image grid — and assert that the compiled
-program holds the Pallas custom call. The topology is described inside
-a fixture, never at import, so only the worker that runs this file
-loads the TPU library.
+program holds the Pallas custom call. The paged decode kernel is also
+compiled at the benchmark cell's shapes, where the compiled program
+must keep the instruction name the benchmark's trace reduction looks
+for. The topology is described inside a fixture, never at import, so
+only the worker that runs this file loads the TPU library.
 """
+import json
 import os
+import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -57,17 +62,15 @@ def _compiled_text(fn, *shapes):
     return jax.jit(fn).lower(*shapes).compile().as_text()
 
 
-@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
-def test_paged_decode_compiles(spec, kv_dtype):
+def _paged_decode_text(spec, kv_dtype, B, n, P, ps=16, h=H, hkv=HKV, hd=HD):
+    """Compiled HLO text of the paged decode kernel: B rows of n pages
+    of ps tokens from a pool of P pages, bf16 or int8 with scales."""
     sdtype = {"bf16": jnp.bfloat16, "int8": jnp.int8}[kv_dtype]
-    ps = PagedKVConfig().page_size
-    n = CACHE_LEN // ps
-    P = SLOTS * n + 1
-    shapes = [spec((SLOTS, 1, H, HD), jnp.bfloat16),
-              spec((P, ps, HKV, HD), sdtype), spec((P, ps, HKV, HD), sdtype),
-              spec((SLOTS, n), jnp.int32), spec((SLOTS,), jnp.int32)]
+    shapes = [spec((B, 1, h, hd), jnp.bfloat16),
+              spec((P, ps, hkv, hd), sdtype), spec((P, ps, hkv, hd), sdtype),
+              spec((B, n), jnp.int32), spec((B,), jnp.int32)]
     if kv_dtype == "int8":
-        shapes += [spec((P, ps, HKV), jnp.float32)] * 2
+        shapes += [spec((P, ps, hkv), jnp.float32)] * 2
 
         def fn(q, kp, vp, bt, ln, ks, vs):
             return pdec.paged_decode_attention(q, kp, vp, bt, ln,
@@ -75,7 +78,33 @@ def test_paged_decode_compiles(spec, kv_dtype):
     else:
         def fn(q, kp, vp, bt, ln):
             return pdec.paged_decode_attention(q, kp, vp, bt, ln)
-    assert "tpu_custom_call" in _compiled_text(fn, *shapes)
+    return _compiled_text(fn, *shapes)
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_paged_decode_compiles(spec, kv_dtype):
+    ps = PagedKVConfig().page_size
+    n = CACHE_LEN // ps
+    text = _paged_decode_text(spec, kv_dtype, SLOTS, n, SLOTS * n + 1, ps)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_paged_decode_compiles_at_cell_shapes(spec, kv_dtype):
+    """qwen3-0.6b.reasoning-backlog: 24 slots, tables of 80 pages of 16,
+    a pool of 1800 pages; the custom call keeps the name that
+    ``bench/configs/qwen3-0.6b.json``'s kernel regex matches."""
+    cell = json.loads((Path(__file__).parents[1] / "bench" / "configs"
+                       / "qwen3-0.6b.json").read_text())
+    pattern = re.compile(cell["kernels"]["paged_decode"])
+    s = cell["sizes"]
+    text = _paged_decode_text(spec, kv_dtype, 24, 80, 1800, 16,
+                              s["num_heads"], s["num_kv_heads"],
+                              s["head_dim"])
+    assert "tpu_custom_call" in text
+    kernel = [ln.strip() for ln in text.splitlines()
+              if pattern.match(ln.strip())]
+    assert kernel and all("tpu_custom_call" in ln for ln in kernel), kernel
 
 
 def test_flash_prefill_2048_compiles(spec):
