@@ -52,13 +52,18 @@ def _pad(x: np.ndarray, fill: int, to: int = 128) -> np.ndarray:
 def compare(params, config: dict, sampling: dict, sample: List[dict],
             quant: Optional[str] = None) -> Dict[str, float]:
     """The numbers compared, over ``sample`` (requests with their prompt
-    and served candidates), by the configuration's reference module
-    ``reference/<name>.py``."""
+    and served candidates, and the ``pixels`` of the image each was
+    served with, if any), by the configuration's reference module
+    ``reference/<name>.py``. An image goes to the reference as
+    ``image=``; positions index the text stream either way, and the
+    reference places the image's span itself."""
     ref = importlib.import_module(f"bench.reference.{config['reference']}")
     sizes = config["sizes"]
     gap, tokens = 0.0, 0
     for r in sample:
         prompt = np.asarray(r["prompt"], np.int32)
+        image = {} if r.get("pixels") is None else \
+            {"image": jnp.asarray(r["pixels"], jnp.float32)}
         for c in r["cands"]:
             toks = np.asarray(c["tokens"], np.int32)
             n = len(toks)
@@ -69,7 +74,7 @@ def compare(params, config: dict, sampling: dict, sample: List[dict],
 
             def logprobs(q):
                 z = ref.logits_at(params, sizes, jnp.asarray(seq),
-                                  jnp.asarray(pos), q)[:n]
+                                  jnp.asarray(pos), q, **image)[:n]
                 return ref.sampled_logprobs(z, jnp.asarray(toks), **sampling)
             want = float(jnp.sum(logprobs(None)))
             mine = c["sum_lp"] if quant is None else \

@@ -73,6 +73,29 @@ def prefill_flops(s: dict, L: int, ctx0: int = 0) -> float:
                  + 2 * s["d_model"] * s["vocab_size"])
 
 
+def vision_encode_flops(s: dict) -> float:
+    """One image through the vision tower and its projector, from
+    ``s["vision"]`` alone: ``image_h`` x ``image_w`` x ``channels``
+    pixels cut into ``patch``-square patches and embedded; ``tokens``
+    (the patches plus any class token; default the patches) through
+    ``num_layers`` bidirectional layers of width ``d_model`` (q, k, v, o
+    projections, every token attending to every token) with a two-matrix
+    MLP of ``d_ff``; then each ``[d_in, d_out]`` of ``projector`` applied
+    to the tokens the tower hands on, ``num_evidence_tokens`` (after any
+    pixel shuffle). Norms, activations and the softmax are not
+    counted."""
+    v = s["vision"]
+    d = v["d_model"]
+    patches = (v["image_h"] // v["patch"]) * (v["image_w"] // v["patch"])
+    tokens = v.get("tokens", patches)
+    embed = 2 * patches * v["patch"] ** 2 * v["channels"] * d
+    layer = tokens * (2 * 4 * d * d + 2 * 2 * d * v["d_ff"]) \
+        + 4 * tokens * tokens * d
+    proj = sum(2 * s["num_evidence_tokens"] * a * b
+               for a, b in v.get("projector", ()))
+    return float(embed + v["num_layers"] * layer + proj)
+
+
 def roofline_seconds(flops: float, nbytes: float, peak: dict
                      ) -> Tuple[float, str]:
     """Least time the chip could take, and which bound sets it."""

@@ -7,8 +7,11 @@ program, and compare what it served with the plain reference.
 Everything particular to a configuration, a traffic mix or a per-layer
 metric lives in a file of its own that is found by name:
 ``configs/<config>.json``, ``traffic/<mix>.json`` (whose generator kinds
-are modules under ``gen/``), ``limits/<cell>.json`` and
-``metrics/<metric>.py``.
+are modules under ``gen/``), ``limits/<cell>.json``,
+``metrics/<metric>.py`` and the configuration's plain reference
+``reference/<name>.py``. A mix with an ``images`` group gives every
+request an image (``images.py``): the program's vision tower encodes it,
+and the reference is handed the same pixels.
 
 Traffic is a closed loop: ``clients`` callers, each sending its next
 request once the previous one is answered. The window opens after a
@@ -30,6 +33,8 @@ from pathlib import Path
 from typing import Any, Dict, List, Optional
 
 import numpy as np
+
+from bench import images
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -77,23 +82,41 @@ def serve_flags(config: dict, mix: dict, seed: int) -> List[str]:
 
 class Traffic:
     """The mix's requests, drawn from the seed: a fixed multiset of
-    prompt lengths in an order drawn from the seed, and the prompts."""
+    prompt lengths in an order drawn from the seed, the prompts, and
+    with an ``images`` group each request's image id (its pixels at
+    the ``vision`` sizes, drawn when the request is made)."""
 
-    def __init__(self, mix: dict, vocab: int, seed: int):
-        self.vocab, self.seed = vocab, seed
+    def __init__(self, mix: dict, vocab: int, seed: int,
+                 vision: Optional[dict] = None):
+        self.vocab, self.seed, self.vision = vocab, seed, vision
         rng = np.random.default_rng([seed, 1])
         self.n = mix["arrivals"]["requests"]
         lk = mix["prompt_len"]
         self.lengths = gen(lk["kind"]).draw(lk, self.n, rng)
+        self.image_ids = None
+        if "images" in mix:
+            if vision is None:
+                raise SystemExit("a mix with images needs the "
+                                 "configuration's sizes.vision")
+            self.image_ids = images.ids(mix["images"], self.n, seed)
 
     def prompt(self, i: int, length: Optional[int] = None) -> np.ndarray:
         r = np.random.default_rng([self.seed, 2, i])
         L = int(self.lengths[i]) if length is None else length
         return r.integers(2, self.vocab, size=L).astype(np.int32)
 
-    def request(self, i: int, uid: int, length: Optional[int] = None):
+    def image_of(self, i: int) -> Optional[int]:
+        return None if self.image_ids is None else int(self.image_ids[i])
+
+    def request(self, i: int, uid: int, length: Optional[int] = None,
+                image: Optional[int] = None):
+        """Request ``i`` under ``uid``; its image is ``image`` (an id)
+        or, by default, the one the mix gives request ``i``."""
         from repro.serving import Request
-        return Request(uid=uid, prompt=self.prompt(i, length))
+        image = self.image_of(i) if image is None else image
+        pixels = None if image is None else \
+            images.pixels(self.seed, image, self.vision)
+        return Request(uid=uid, prompt=self.prompt(i, length), image=pixels)
 
 
 # ---------------------------------------------------------------------------
@@ -204,12 +227,17 @@ class Run:
         shapes = jax.eval_shape(layout)
         self.cfg, self.model = box["cfg"], box["model"]
         self._check_sizes()
-        self.params = make_params(shapes, self.seed)
+        self.params = make_params(shapes, self.seed, self.config.get("init"))
         jax.block_until_ready(self.params)
         self.eng = build_engine(args, self.model, self.params)
 
     def _check_sizes(self):
-        """The configuration file's sizes are what the program runs."""
+        """The configuration file's sizes are what the program runs.
+        Where the file has ``sizes.vision``, each of its keys is the
+        program's ``cfg.vision`` field of that name, and the evidence
+        span (``num_evidence_tokens`` x ``evidence_dim``) is the
+        program's too; ``tokens`` and ``projector`` only describe the
+        tower's work to ``costs.vision_encode_flops``."""
         c, s = self.cfg, self.config["sizes"]
         ran = {"num_layers": c.num_layers, "d_model": c.d_model,
                "num_heads": c.num_heads, "num_kv_heads": c.num_kv_heads,
@@ -217,7 +245,16 @@ class Run:
                "vocab_size": c.vocab_size, "qk_norm": c.qk_norm,
                "rope_theta": c.rope_theta, "norm_eps": c.norm_eps,
                "tie_embeddings": c.tie_embeddings, "mlp": c.mlp_activation}
-        diff = {k: (s.get(k), v) for k, v in ran.items() if s.get(k) != v}
+        declared = {k: s.get(k) for k in ran}
+        if "vision" in s:
+            for k in ("num_evidence_tokens", "evidence_dim"):
+                ran[k], declared[k] = getattr(c, k), s.get(k)
+            for k, want in s["vision"].items():
+                if k not in ("tokens", "projector"):
+                    ran[f"vision.{k}"] = getattr(c.vision, k, "no such field")
+                    declared[f"vision.{k}"] = want
+        diff = {k: (declared[k], v) for k, v in ran.items()
+                if declared[k] != v}
         if diff:
             raise SystemExit(f"configuration sizes differ from the program's "
                              f"(file, program): {diff}")
@@ -244,21 +281,27 @@ class Run:
         requests whose rounds end together); a request admitted with
         each count of candidates short of a round (when fewer slots are
         free); and the first-token sampler of a later round, which adds
-        the round's guidance bias."""
+        the round's guidance bias. A mix with images sends an image of
+        its own with every warm-up request (so the page shapes count
+        the evidence span ahead of the prompt), and walks
+        ``_warm_images`` besides."""
         mix, eng = self.mix, self.eng
         support = gen(mix["prompt_len"]["kind"]).support(mix["prompt_len"])
-        self.uid = 10 ** 9
+        self.uid, self.warm_images = 10 ** 9, 0
         ps, per = eng.page_size, eng._per_round()
+        ne = self.cfg.num_evidence_tokens if traffic.image_ids is not None \
+            else 0
         clock, spent = time.perf_counter, {}
         t = clock()
         # a prompt for each count of full pages (with a partial tail
         # where the support has one), a slotful of requests at a time
         reps: Dict[int, int] = {}
         for L in support:
-            if L // ps not in reps or L % ps:
-                reps.setdefault(L // ps, L)
-                if L % ps and reps[L // ps] % ps == 0:
-                    reps[L // ps] = L
+            full, tail = divmod(L + ne, ps)
+            if full not in reps or tail:
+                reps.setdefault(full, L)
+                if tail and (reps[full] + ne) % ps == 0:
+                    reps[full] = L
         lengths = sorted(reps.values())
         for i in range(0, len(lengths), eng.B // per):
             await self._serve_batch(
@@ -266,6 +309,9 @@ class Run:
                      for L in lengths[i:i + eng.B // per]],
                 cancel_after_prefill=True)
         spent["pages"], t = clock() - t, clock()
+        if ne:
+            await self._warm_images(fe, traffic, support)
+            spent["images"], t = clock() - t, clock()
         # every bucket at every row count, buckets sharing a batch
         buckets: Dict[int, List[int]] = {}
         for L in support:
@@ -279,7 +325,7 @@ class Run:
                      for b, nb in batch for j in range(nb)],
                 cancel_after_prefill=True)
         spent["prefill rows"], t = clock() - t, clock()
-        L = min((L for L in support if L % ps), default=min(support))
+        L = min((L for L in support if (L + ne) % ps), default=min(support))
         for fill in _pack([(k, k) for k in range(1, mix["warm_finish"] + 1)],
                           eng.B):
             held = await self._admit(fe, traffic, L, eng.B // per)
@@ -302,8 +348,38 @@ class Run:
                  f"{len(support)} prompt lengths; seconds: " + ", ".join(
                      f"{k} {v:.1f}" for k, v in spent.items()))
 
-    def _warm_request(self, traffic: Traffic, length: int):
-        req = traffic.request(0, self.uid, length=length)
+    async def _warm_images(self, fe, traffic: Traffic, support: list):
+        """An image request at every prompt length, each with a fresh
+        image: the tower, the prefill of the evidence span, and the host
+        ops of an image request's set-up, which compile per prompt
+        length. Then the same images again under new prompts: the
+        feature memo's hit and, with the prefix cache on, the prefill
+        over the cached image pages at every suffix length."""
+        eng = self.eng
+        step = eng.B // eng._per_round()
+        for i in range(0, len(support), step):
+            batch = [(L, self._fresh_image()) for L in support[i:i + step]]
+            for _ in range(2 if eng.prefix_cache or i == 0 else 1):
+                await self._serve_batch(
+                    fe, [self._warm_request(traffic, L, g) for L, g in batch],
+                    cancel_after_prefill=True)
+
+    def _fresh_image(self) -> int:
+        self.warm_images += 1
+        return images.WARM_ID0 + self.warm_images
+
+    def _warm_request(self, traffic: Traffic, length: int,
+                      image: Optional[int] = None):
+        """A warm-up request of ``length`` prompt tokens. In a mix with
+        images it carries ``image`` (by default a fresh one, outside
+        every pool) and a prompt of its own, so that no two warm-up
+        requests share a cached page the window's would not."""
+        if traffic.image_ids is None:
+            req = traffic.request(0, self.uid, length=length)
+        else:
+            req = traffic.request(self.uid, self.uid, length=length,
+                                  image=self._fresh_image() if image is None
+                                  else image)
         self.uid += 1
         return req
 
@@ -370,7 +446,8 @@ class Run:
         from repro.serving.frontend import AsyncServeFrontend
 
         eng, mix = self.eng, self.mix
-        self.traffic = traffic = Traffic(mix, self.cfg.vocab_size, self.seed)
+        self.traffic = traffic = Traffic(mix, self.cfg.vocab_size, self.seed,
+                                         self.config["sizes"].get("vision"))
         self.rec = Recorder(eng) if self.trace else None
         # the candidates of each request's first admission: sampled with
         # no guidance bias, so the reference can follow them
@@ -384,8 +461,9 @@ class Run:
         eng._admit = first_round
         orig_pump = eng.pump
         clock = time.perf_counter
-        # inside the window: pumps over a second and garbage-collector
-        # passes, to tell a stall's cause
+        # inside the window: pumps over a second (with the time of each
+        # engine phase inside them) and garbage-collector passes, to tell
+        # a stall's cause
         self.stalls: Optional[Dict[str, list]] = None
 
         def pump():
@@ -395,6 +473,10 @@ class Run:
             if self.stalls is not None and clock() - t > 1.0:
                 self.stalls["pumps"].append(
                     (round(t - self.window["t0_abs"], 3), round(clock() - t, 3)))
+                last = eng.span_stats()["last_pump"] or {"children": {}}
+                self.stalls["phases"].append(
+                    {k: round(ns * 1e-9, 3)
+                     for k, ns in last["children"].items()})
             return out
         eng.pump = pump
 
@@ -435,8 +517,9 @@ class Run:
         first = self.first_round.pop(i)
         cands = [{"tokens": np.asarray(c["tokens"]), "sum_lp": c["sum_lp"]}
                  for c in res.candidates if c["uid"] in first]
-        self.finished.append({"i": i, "prompt": req.prompt, "cands": cands,
-                              "t_done": tr["t_done"]})
+        self.finished.append({"i": i, "prompt": req.prompt,
+                              "image": self.traffic.image_of(i),
+                              "cands": cands, "t_done": tr["t_done"]})
 
     async def _progress(self, clock, every: float = 5.0):
         """A line on standard error every ``every`` seconds: decode steps,
@@ -463,7 +546,7 @@ class Run:
         self.window["t0_abs"] = t0
         self.window["compiles0"] = self.clock.count
         self.clock.names = []
-        self.stalls = {"pumps": [], "gc": []}
+        self.stalls = {"pumps": [], "phases": [], "gc": []}
         eng.reset_stats()
         self.window["live0"] = self._live_tokens()
         if self.trace:
@@ -473,6 +556,7 @@ class Run:
             jax.profiler.start_trace(self.trace_dir,
                                      profiler_options=profile_options())
             self.rec.on = True
+            self.window["trace_sched0"] = eng.sched_stats()
             with jax.profiler.TraceAnnotation("window"):
                 await asyncio.sleep(max(0.0, t0 + self.trace_seconds - clock()))
                 self.window["trace_s"] = clock() - t0
@@ -499,7 +583,8 @@ class Run:
         e = self.eng
         return {"steps": e.total_steps, "launches": e.macro_launches,
                 "tokens": e.total_tokens + self._live_tokens(),
-                "sched": e.sched_stats(), "slots": e.B, "kv": e.kv_stats()}
+                "sched": e.sched_stats(), "slots": e.B, "kv": e.kv_stats(),
+                "spans": e.span_stats()}
 
     async def _closed(self, fe, clock):
         arr = self.mix["arrivals"]
@@ -556,8 +641,11 @@ class Run:
             pd = trace_reduce.load(self.trace_dir)
             ctx["trace"] = None if pd is None else trace_reduce.reduce(
                 pd, self.config["kernels"])
+            s0, s1 = w["trace_sched0"], w["trace_counters"]["sched"]
             ctx.update(trace_s=w["trace_s"],
                        trace_counters=w["trace_counters"],
+                       trace_sched={k: v - s0[k] for k, v in s1.items()
+                                    if isinstance(v, (int, float))},
                        decode_ctxs=self.rec.decode_contexts(),
                        prefills=self.rec.prefills, slots=self.rec.slots)
             import shutil
@@ -622,6 +710,13 @@ def execute(spec: dict, seed: int, seconds: float, trace: bool,
             f"longest {max(st['gc'], default=0.0):.3f} s, total "
             f"{sum(st['gc']):.3f} s; pumps over 1 s (opened at, took): "
             f"{st['pumps']}")
+    run.say(f"inside the window: engine phases of each pump over 1 s "
+            f"(seconds): {st['phases']}")
+    sc = w["counters"]["sched"]
+    run.say(f"inside the window: {sc['prefill_calls']} prefill calls over "
+            f"{sc['prefill_tokens']} tokens, {sc['image_encodes']} image "
+            f"encodes, {sc['image_feat_hits']} feature-memo hits, prefix "
+            f"cache {w['counters']['kv'].get('prefix_cache')}")
     ctx = run.context()
     names = spec["per_layer"] if trace else spec["end_to_end"]
     metrics = {}
@@ -659,6 +754,10 @@ def judge(spec, seed, params, finished, extra, quant=None) -> dict:
     mix, config = spec["mix"], spec["config"]
     rng = np.random.default_rng([seed, 4])
     sample = check.pick_sample(finished, mix["check_requests"], rng)
+    # the pixels each sampled request was served with, drawn again
+    sample = [r if r.get("image") is None else dict(
+        r, pixels=images.pixels(seed, r["image"], config["sizes"]["vision"]))
+        for r in sample]
     numbers = check.compare(params, config, mix["sampling"], sample, quant)
     numbers.update(extra)
     ok, lines = check.verdict(numbers, spec["limits"], list(spec["limits"]))

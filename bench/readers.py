@@ -47,8 +47,8 @@ def paged_decode_roofline(ctx) -> Optional[float]:
 
 
 def mfu(ctx) -> Optional[float]:
-    """Required model FLOPs of every prefill and decode step in the
-    traced window, over its seconds times the peak."""
+    """Required model FLOPs of every prefill, decode step and image
+    encode in the traced window, over its seconds times the peak."""
     if ctx.get("trace") is None:
         return None
     s = ctx["sizes"]
@@ -59,6 +59,15 @@ def mfu(ctx) -> Optional[float]:
         flops += per_tok * len(c) + pair * float(np.sum(c))
     for L in ctx["prefills"]:
         flops += costs.prefill_flops(s, L)
+    # what the Recorder does not see, from the engine's counters over
+    # the traced window: each image through the tower, and the tokens of
+    # prefills it does not wrap (one-row, prefix-cache suffix, a chunked
+    # prompt's last chunk), counted through the layers' matrices only
+    d = ctx.get("trace_sched") or {}
+    if "vision" in s:
+        flops += d.get("image_encodes", 0) * costs.vision_encode_flops(s)
+    rest = d.get("prefill_tokens", 0) - sum(ctx["prefills"])
+    flops += 2 * s["num_layers"] * costs.layer_matmul_params(s) * max(rest, 0)
     return 100.0 * flops / (ctx["trace_s"] * ctx["peak"]["bf16_flops"])
 
 

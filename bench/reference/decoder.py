@@ -59,25 +59,40 @@ def _rope(x, pos, theta):
     return jnp.concatenate([a * c - b * s, a * s + b * c], -1)
 
 
+def scalar_sizes(s: dict) -> tuple:
+    """The sizes' scalar entries, hashable (a static jit argument)."""
+    return tuple(sorted((k, v) for k, v in s.items()
+                        if not isinstance(v, dict)))
+
+
 def logits_at(params, s: dict, tokens, out_pos, quant=None):
     """Logits (n, V) at sequence positions ``out_pos`` (n,) of one
     sequence of ``tokens`` (L,)."""
     with jax.default_matmul_precision("highest"):
-        scalars = tuple(sorted((k, v) for k, v in s.items()
-                               if not isinstance(v, dict)))
-        return _logits_jit(params, scalars, tokens, out_pos, quant)
+        return _logits_jit(params, scalar_sizes(s), tokens, out_pos, quant)
 
 
 @functools.partial(jax.jit, static_argnums=(1, 4))
 def _logits_jit(params, s_items, tokens, out_pos, quant):
-    s = dict(s_items)
+    table = embedding(params, quant)
+    return decode(params, dict(s_items), table[tokens], out_pos, quant,
+                  table)
+
+
+def embedding(params, quant=None):
+    """The token embedding table, as the model reads it."""
+    return _w(params["embed"]["table"], quant, axis=-1)
+
+
+def decode(params, s: dict, x, out_pos, quant, table):
+    """The decoder over the embeddings ``x`` (L, d): every layer, the
+    final norm and the head (``table`` transposed where tied), at
+    positions ``out_pos``. Traced inside the caller's jit."""
     if params["tail"]:
         raise ValueError("the reference expects one stacked layer group")
     eps = s["norm_eps"]
     H, Hkv = s["num_heads"], s["num_kv_heads"]
     hd = s.get("head_dim") or s["d_model"] // H
-    table = _w(params["embed"]["table"], quant, axis=-1)
-    x = table[tokens]
     L = x.shape[0]
     pos = jnp.arange(L)
     causal = pos[:, None] >= pos[None, :]

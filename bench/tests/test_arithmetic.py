@@ -115,3 +115,27 @@ def test_prefill_batches_never_merge_a_bucket():
     for b in batches:
         assert sum(n for _, n in b) <= 8
         assert len({k for k, _ in b}) == len(b)
+
+
+def test_mfu_adds_tower_and_unwrapped_prefill_work():
+    base = ctx_of(trace={"kernel_s": {}, "busy_s": 1, "window_s": 1},
+                  sizes=SIZES, peak=PEAK, decode_ctxs=[np.array([5])],
+                  prefills=[3], trace_s=1.0)
+    text = read_metric("mfu.tokens", base)[0]
+    # the text cell's counters: every prefill token wrapped, no image
+    base["trace_sched"] = {"image_encodes": 0, "prefill_tokens": 3}
+    assert read_metric("mfu.tokens", base)[0] == text
+    sizes = dict(SIZES, num_evidence_tokens=4, vision={
+        "image_h": 8, "image_w": 8, "channels": 3, "patch": 4,
+        "num_layers": 1, "d_model": 16, "num_heads": 2, "d_ff": 32,
+        "projector": [[16, 64]]})
+    tower = (2 * 4 * 48 * 16 + 4 * (8 * 16 * 16 + 4 * 16 * 32)
+             + 4 * 4 * 4 * 16 + 2 * 4 * 16 * 64)
+    ctx = dict(base, sizes=sizes,
+               trace_sched={"image_encodes": 3, "prefill_tokens": 3 + 10})
+    # 3 encodes, and 10 prefill tokens on paths the Recorder does not
+    # wrap, through the layers' matrices: 2 x 2 layers x params
+    extra = 3 * tower + 10 * 2 * 2 * (64 * 64 * 2 + 64 * 32 * 2
+                                      + 3 * 64 * 128)
+    assert read_metric("mfu.tokens", ctx)[0] == pytest.approx(
+        text + 100 * extra / 1e12)

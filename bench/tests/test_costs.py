@@ -57,3 +57,41 @@ def test_model_step_flops():
     assert costs.prefill_flops(IVL, 16, 1024) == (
         2 * 24 * 62_914_560 * 16 + 24 * 4 * 16 * 128 * pairs
         + 2 * 2048 * 92553)
+
+
+# the repo's stand-in tower at internvl2-2b's serving widths
+# (configs/internvl2_2b.py), and InternViT-300M-448px with its pixel
+# shuffle and MLP projector (arXiv:2404.16821)
+STAND_IN = {"num_evidence_tokens": 256, "vision": {
+    "image_h": 448, "image_w": 448, "channels": 3, "patch": 28,
+    "num_layers": 4, "d_model": 768, "num_heads": 12, "d_ff": 3072,
+    "projector": [[768, 2048]]}}
+INTERNVIT = {"num_evidence_tokens": 256, "vision": {
+    "image_h": 448, "image_w": 448, "channels": 3, "patch": 14,
+    "num_layers": 24, "d_model": 1024, "num_heads": 16, "d_ff": 4096,
+    "tokens": 1025, "projector": [[4096, 2048], [2048, 2048]]}}
+
+
+def test_vision_encode_flops_of_the_stand_in_tower():
+    # 16 x 16 patches of 28 x 28 x 3 pixels embedded to 768
+    embed = 2 * 256 * 2352 * 768
+    # per layer: q, k, v, o (4 x 768^2) and the MLP (2 x 768 x 3072) for
+    # 256 tokens, scores and values over 256 x 256 pairs
+    layer = 256 * 2 * (4 * 768 ** 2 + 2 * 768 * 3072) + 2 * 2 * 256 ** 2 * 768
+    assert layer == 3_825_205_248
+    proj = 2 * 256 * 768 * 2048
+    assert costs.vision_encode_flops(STAND_IN) == embed + 4 * layer + proj \
+        == 17_030_971_392
+
+
+def test_vision_encode_flops_of_internvit_300m():
+    # 32 x 32 patches of 14 x 14 x 3 embedded to 1024; a class token
+    # makes 1025 tokens in each of 24 layers
+    embed = 2 * 1024 * 588 * 1024
+    layer = 1025 * 2 * (4 * 1024 ** 2 + 2 * 1024 * 4096) \
+        + 2 * 2 * 1025 ** 2 * 1024
+    # pixel shuffle 0.5: 256 tokens of 4096, then 4096->2048->2048
+    proj = 2 * 256 * (4096 * 2048 + 2048 * 2048)
+    want = embed + 24 * layer + proj
+    assert want == 730_035_486_720
+    assert costs.vision_encode_flops(INTERNVIT) == want

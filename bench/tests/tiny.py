@@ -4,23 +4,34 @@ four callers. Used by the tests only; the chip runs the cells as they
 stand."""
 from __future__ import annotations
 
+import copy
 import json
+from typing import Union
 
 from bench import harness
 
+VISION = ("image_h", "image_w", "patch", "channels", "num_layers",
+          "d_model", "num_heads", "d_ff")
 
-def tiny_spec(config: str, traffic: str, limits: dict, max_new: int = 32,
-              slots: int = 8, clients: int = 4, stagger: int = 2) -> dict:
+
+def _file(group: str, name: Union[str, dict]) -> dict:
+    if isinstance(name, dict):
+        return copy.deepcopy(name)
+    return json.loads((harness.BENCH / group / f"{name}.json").read_text())
+
+
+def tiny_spec(config: Union[str, dict], traffic: Union[str, dict],
+              limits: dict, max_new: int = 32, slots: int = 8,
+              clients: int = 4, stagger: int = 2) -> dict:
     """The spec ``harness.load_cell`` would give a cell of ``config``
-    under ``traffic`` (files under ``bench/``), cut down."""
+    under ``traffic`` (files under ``bench/``, or their contents),
+    cut down: the sizes the reduced program runs, tower included."""
     import jax
     from repro.launch.serve import build_model, build_parser
 
-    spec = {"cell": {"name": f"{config}.{traffic}", "chips": 1},
-            "config": json.loads((harness.BENCH / "configs" /
-                                  f"{config}.json").read_text()),
-            "mix": json.loads((harness.BENCH / "traffic" /
-                               f"{traffic}.json").read_text()),
+    spec = {"cell": {"name": "tiny", "chips": 1},
+            "config": _file("configs", config),
+            "mix": _file("traffic", traffic),
             "limits": dict(limits),
             "end_to_end": [{"name": "setup_s"}], "per_layer": []}
     conf, mix = spec["config"], spec["mix"]
@@ -46,6 +57,11 @@ def tiny_spec(config: str, traffic: str, limits: dict, max_new: int = 32,
              num_heads=c.num_heads, num_kv_heads=c.num_kv_heads,
              head_dim=c.resolved_head_dim, d_ff=c.d_ff,
              vocab_size=c.vocab_size)
+    if "vision" in s:
+        s.update(num_evidence_tokens=c.num_evidence_tokens,
+                 evidence_dim=c.evidence_dim)
+        s["vision"] = {k: getattr(c.vision, k) for k in VISION}
+        s["vision"]["projector"] = [[c.vision.d_model, c.evidence_dim]]
     return spec
 
 
